@@ -159,6 +159,9 @@ def main(emit):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)

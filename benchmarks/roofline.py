@@ -1,16 +1,15 @@
 """Roofline derivation from the dry-run JSONs (EXPERIMENTS.md §Roofline).
 
-Hardware model (TPU v5e per chip):
-  peak bf16 compute : 197 TFLOP/s
-  HBM bandwidth     : 819 GB/s
-  ICI link bandwidth: ~50 GB/s per link
+Hardware model: per-chip peaks keyed by the `device_kind` JAX reports
+(`PEAKS`, each row with its source). A kind that is not in the table raises;
+no device is priced at another's peaks.
 
 Terms (seconds; cost_analysis / HLO collective bytes are PER-DEVICE, so
 dividing by per-chip rates directly gives the per-step time bound — equal to
 the global-quantity formulas in the task statement divided through by chips):
-  compute    = flops_per_device / PEAK_FLOPS
-  memory     = bytes_per_device / HBM_BW
-  collective = collective_bytes_per_device / ICI_BW
+  compute    = flops_per_device / peak flops
+  memory     = bytes_per_device / HBM bandwidth
+  collective = collective_bytes_per_device / ICI link bandwidth
 """
 from __future__ import annotations
 
@@ -19,9 +18,24 @@ import json
 import os
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # B/s per chip
-ICI_BW = 50e9                # B/s per link
+#: `jax.Device.device_kind` of one TPU v5e chip
+V5E = "TPU v5 lite"
+
+#: per-chip peaks by device kind: "flops" (bf16 FLOP/s), "hbm_bw" (B/s),
+#: "ici_bw" (B/s per chip-to-chip link)
+PEAKS: Dict[str, Dict[str, float]] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM
+    # at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect over 4 links.
+    V5E: {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The `PEAKS` row of `device_kind`; an unknown kind raises KeyError."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no roofline peaks for device_kind {device_kind!r}; "
+                       f"known kinds: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 def load_cells(directory: str = "results/dryrun") -> List[Dict]:
@@ -48,10 +62,11 @@ def model_flops(cell: Dict) -> float:
 def roofline_terms(cell: Dict) -> Optional[Dict]:
     if cell.get("status") != "ok":
         return None
+    pk = peaks(V5E)  # the dry-run cells are priced as v5e pods
     chips = cell["chips"]
-    compute_s = cell["flops_per_device"] / PEAK_FLOPS
-    memory_s = cell["bytes_per_device"] / HBM_BW
-    coll_s = cell["collective_bytes_per_device"]["total"] / ICI_BW
+    compute_s = cell["flops_per_device"] / pk["flops"]
+    memory_s = cell["bytes_per_device"] / pk["hbm_bw"]
+    coll_s = cell["collective_bytes_per_device"]["total"] / pk["ici_bw"]
     dominant = max(
         ("compute", compute_s), ("memory", memory_s), ("collective", coll_s),
         key=lambda kv: kv[1],
@@ -66,7 +81,7 @@ def roofline_terms(cell: Dict) -> Optional[Dict]:
         "useful_ratio": (mf / hlo_global) if hlo_global else 0.0,
         "bound_s": max(compute_s, memory_s, coll_s),
         # fraction of roofline-limited time that is useful model compute
-        "roofline_fraction": (mf / chips / PEAK_FLOPS) / max(compute_s, memory_s, coll_s)
+        "roofline_fraction": (mf / chips / pk["flops"]) / max(compute_s, memory_s, coll_s)
         if max(compute_s, memory_s, coll_s) > 0 else 0.0,
         "temp_gib": cell.get("memory_analysis", {}).get("temp_size_in_bytes", 0) / 2**30,
     }

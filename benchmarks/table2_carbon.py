@@ -141,4 +141,7 @@ def _cli(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(_cli())

@@ -14,6 +14,9 @@ import numpy as np
 from repro.core import make
 from repro.pool import make_vec
 from repro.rl.ppo import PPOConfig, train
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--updates", type=int, default=40)

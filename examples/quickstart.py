@@ -7,6 +7,9 @@ import time
 import jax
 
 from repro import cairl  # <- the one-line migration the paper advertises
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # ---- Listing 2: classic Gym loop (drop-in) ---------------------------------
 e = cairl.make("CartPole-v1")          # was: gym.make("CartPole-v1")

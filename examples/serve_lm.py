@@ -10,6 +10,9 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.models import lm
 from repro.serving.engine import Request, ServeEngine
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 cfg = get_config("yi-6b", reduced=True)
 params = lm.init_params(cfg, jax.random.PRNGKey(0))

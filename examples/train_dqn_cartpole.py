@@ -16,6 +16,9 @@ from repro.configs.cairl_dqn import TUNED
 from repro.core import make
 from repro.rl.dqn import greedy_returns, train_compiled
 from repro.sustainability.impact import ImpactTracker
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=60000)

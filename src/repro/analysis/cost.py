@@ -51,13 +51,11 @@ from repro.core.registry import registered, spec
 from repro.launch.hlo_analysis import analyze_hlo, peak_live_bytes
 from repro.sustainability.impact import ACCELERATOR_TDP_WATTS, StaticImpact
 
-try:  # benchmarks/ is a repo-root package; importable from make targets,
-    # but src-only contexts fall back to the same documented constants
-    from benchmarks.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
-except ImportError:  # pragma: no cover - mirrors benchmarks/roofline.py
-    PEAK_FLOPS = 197e12  # bf16 FLOP/s per chip (TPU v5e)
-    HBM_BW = 819e9       # B/s per chip
-    ICI_BW = 50e9        # B/s per link
+from benchmarks.roofline import V5E, peaks
+
+#: the chip the static model prices a step on: one TPU v5e
+TARGET_DEVICE_KIND = V5E
+_PEAKS = peaks(TARGET_DEVICE_KIND)
 
 #: backends swept in smoke mode (the two distinct step-kernel paths; async/
 #: sharded wrap the same cores and stay in the full sweep + audit matrix)
@@ -131,13 +129,13 @@ def _roofline(flops_ps: float, bytes_ps: float,
     """Static roofline position of one env step against the per-chip
     ceilings: per-term time bounds, the binding term, and where the cell's
     arithmetic intensity sits relative to the machine balance point."""
-    compute_s = flops_ps / PEAK_FLOPS
-    memory_s = bytes_ps / HBM_BW
-    collective_s = coll_ps / ICI_BW
+    compute_s = flops_ps / _PEAKS["flops"]
+    memory_s = bytes_ps / _PEAKS["hbm_bw"]
+    collective_s = coll_ps / _PEAKS["ici_bw"]
     terms = (("compute", compute_s), ("memory", memory_s),
              ("collective", collective_s))
     dominant, bound_s = max(terms, key=lambda kv: kv[1])
-    balance = PEAK_FLOPS / HBM_BW  # FLOP/byte where compute == memory time
+    balance = _PEAKS["flops"] / _PEAKS["hbm_bw"]  # FLOP/byte where compute == memory time
     intensity = flops_ps / bytes_ps if bytes_ps else 0.0
     return {
         "compute_s": compute_s, "memory_s": memory_s,
@@ -270,8 +268,10 @@ def run(ids: Optional[Sequence[str]] = None,
             "train_cells": list(train_ids),
             "thresholds": dict(DEFAULT_THRESHOLDS),
             "gated_metrics": list(GATED_METRICS),
-            "ceilings": {"peak_flops": PEAK_FLOPS, "hbm_bw": HBM_BW,
-                         "ici_bw": ICI_BW,
+            "ceilings": {"device_kind": TARGET_DEVICE_KIND,
+                         "peak_flops": _PEAKS["flops"],
+                         "hbm_bw": _PEAKS["hbm_bw"],
+                         "ici_bw": _PEAKS["ici_bw"],
                          "accelerator_watts": ACCELERATOR_TDP_WATTS},
         },
         "rows": rows,

@@ -5,15 +5,9 @@ import functools
 
 import jax
 
+from repro.kernels import on_tpu
 from repro.kernels.attention.flash import flash_attention
 from repro.kernels.attention.ref import attention_ref
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover  # repro: allow[silent-except] backend probe: failure = "not TPU", the safe dispatch default
-        return False
 
 
 @functools.partial(
@@ -32,7 +26,7 @@ def attention(
 ) -> jax.Array:
     """Multi-head GQA attention (B, Hq, Lq, D) × (B, Hkv, Lk, D) -> (B, Hq, Lq, D)."""
     if backend == "auto":
-        backend = "pallas" if _on_tpu() else "jnp"
+        backend = "pallas" if on_tpu() else "jnp"
     if backend == "pallas":
         return flash_attention(q, k, v, causal=causal, window=window,
                                block_q=block_q, block_k=block_k)

@@ -31,16 +31,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.envstep.megastep import megastep_pallas
 from repro.kernels.envstep.ref import megastep_ref
 from repro.kernels.envstep.specs import lookup
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover  # repro: allow[silent-except] backend probe: failure = "not TPU", the safe dispatch default
-        return False
 
 
 def env_megastep(step_rows, state, actions, fresh, fresh_obs, *,
@@ -52,7 +46,7 @@ def env_megastep(step_rows, state, actions, fresh, fresh_obs, *,
     "pallas_interpret" | "jnp".
     """
     if backend == "auto":
-        backend = "pallas" if _on_tpu() else "jnp"
+        backend = "pallas" if on_tpu() else "jnp"
     if backend == "pallas":
         return megastep_pallas(step_rows, state, actions, fresh, fresh_obs,
                                max_steps=max_steps, batch_block=batch_block)

@@ -287,8 +287,9 @@ def _grid_move(pos, act, n_rows, n_cols):
 
 
 def _cell_iota(m):
-    """(m, 1) f32 per-cell index plane; 2-D iota is TPU-native."""
-    return jax.lax.broadcasted_iota(jnp.float32, (m, 1), 0)
+    """(m, 1) f32 per-cell index plane. 2-D iota is TPU-native, but Mosaic
+    builds it only as an integer vector, so it is cast afterwards."""
+    return jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0).astype(jnp.float32)
 
 
 def _frozen_lake_rows(env) -> Callable:

@@ -6,15 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.raster.raster import rasterize_pallas
 from repro.kernels.raster.ref import rasterize_ref
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover  # repro: allow[silent-except] backend probe: failure = "not TPU", the safe dispatch default
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("h", "w", "backend"))
@@ -24,14 +18,39 @@ def rasterize(segs: jax.Array, intens: jax.Array, h: int, w: int, backend: str =
     backend: "auto" (pallas on TPU, jnp elsewhere) | "pallas" | "pallas_interpret" | "jnp".
     """
     if backend == "auto":
-        backend = "pallas" if _on_tpu() else "jnp"
-    if backend == "pallas":
-        return rasterize_pallas(segs, intens, h, w)
-    if backend == "pallas_interpret":
-        return rasterize_pallas(segs, intens, h, w, interpret=True)
+        backend = "pallas" if on_tpu() else "jnp"
+    if backend in ("pallas", "pallas_interpret"):
+        return _frame_batched_pallas(h, w, backend == "pallas_interpret")(
+            segs, intens)
     if backend == "jnp":
         return rasterize_ref(segs, intens, h, w)
     raise ValueError(f"unknown backend {backend!r}")
+
+
+@functools.lru_cache(maxsize=None)
+def _frame_batched_pallas(h: int, w: int, interpret: bool):
+    """`rasterize_pallas` whose vmap is one call over more frames.
+
+    Frames are independent, so a vmapped call (the vmap engine renders one
+    env per lane) folds the vmapped axis into the frame batch. A vmapped
+    `pallas_call` would instead gain a grid axis over blocks that Mosaic
+    cannot tile.
+    """
+
+    @jax.custom_batching.custom_vmap
+    def call(segs, intens):
+        return rasterize_pallas(segs, intens, h, w, interpret=interpret)
+
+    @call.def_vmap
+    def _(axis_size, in_batched, segs, intens):
+        segs, intens = (x if batched else
+                        jnp.broadcast_to(x, (axis_size,) + x.shape)
+                        for x, batched in zip((segs, intens), in_batched))
+        out = call(segs.reshape((-1,) + segs.shape[2:]),
+                   intens.reshape((-1,) + intens.shape[2:]))
+        return out.reshape((axis_size, -1) + out.shape[1:]), True
+
+    return call
 
 
 def rasterize_single(segs: jax.Array, intens: jax.Array, h: int, w: int) -> jax.Array:

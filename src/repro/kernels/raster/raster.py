@@ -8,9 +8,12 @@ evaluated across all 8×128 lanes at once, and the frame lands in the same HBM
 the learner's conv stack reads — no host or PCIe round-trip anywhere.
 
 Tiling: grid over (batch-tile,); each program instance rasterises BB frames.
-The framebuffer block (BB, H, Wp) with W padded to the 128-lane boundary and
-the (BB, S, 8) segment table both sit in VMEM; S is looped with fori_loop so
-VMEM stays O(H·W) regardless of scene complexity.
+The framebuffer block (BB, H, Wp), W padded to the 128-lane boundary, sits in
+VMEM. The scene table — six scalars per segment (x0, y0, x1, y1, radius,
+intensity) — sits in SMEM: the kernel reads it one scalar at a time at
+run-time indices, which the scalar unit does natively and Mosaic refuses
+from VMEM. S is looped with fori_loop so VMEM stays O(H·W) regardless of
+scene complexity.
 """
 from __future__ import annotations
 
@@ -19,26 +22,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _EPS = 1e-8
+#: scalars per segment in the SMEM scene table: x0, y0, x1, y1, radius, intensity
+_FIELDS = 6
 
 
-def _raster_kernel(segs_ref, inten_ref, out_ref, *, h: int, w: int, s: int, bb: int):
+def _raster_kernel(scene_ref, out_ref, *, h: int, w: int, s: int, bb: int):
     softness = 1.0 / h
     # Pixel-centre coordinate planes for the padded (h, wp) tile. TPU needs
-    # >=2D iota; broadcasted_iota is the native VPU form.
+    # >=2D iota, and Mosaic builds it only as an integer vector.
     wp = out_ref.shape[-1]
-    py = (jax.lax.broadcasted_iota(jnp.float32, (h, wp), 0) + 0.5) / h
-    px = (jax.lax.broadcasted_iota(jnp.float32, (h, wp), 1) + 0.5) / w
+    py = (jax.lax.broadcasted_iota(jnp.int32, (h, wp), 0).astype(jnp.float32)
+          + 0.5) / h
+    px = (jax.lax.broadcasted_iota(jnp.int32, (h, wp), 1).astype(jnp.float32)
+          + 0.5) / w
 
     def one_frame(b, _):
         def body(i, fb):
-            x0 = segs_ref[b, i, 0]
-            y0 = segs_ref[b, i, 1]
-            x1 = segs_ref[b, i, 2]
-            y1 = segs_ref[b, i, 3]
-            r = segs_ref[b, i, 4]
-            inten = inten_ref[b, i]
+            base = (b * s + i) * _FIELDS
+            x0 = scene_ref[base]
+            y0 = scene_ref[base + 1]
+            x1 = scene_ref[base + 2]
+            y1 = scene_ref[base + 3]
+            r = scene_ref[base + 4]
+            inten = scene_ref[base + 5]
             dx, dy = x1 - x0, y1 - y0
             l2 = jnp.maximum(dx * dx + dy * dy, _EPS)
             t = jnp.clip(((px - x0) * dx + (py - y0) * dy) / l2, 0.0, 1.0)
@@ -73,18 +82,24 @@ def rasterize_pallas(
         intens = jnp.pad(intens, ((0, bp - b), (0, 0)))
     wp = (w + 127) // 128 * 128  # lane-align the minor dim
 
-    # Pad the segment feature dim to 8 so the VMEM tile is sublane-friendly.
-    segs8 = jnp.concatenate([segs, jnp.zeros((bp, s, 3), segs.dtype)], axis=-1)
+    # One flat f32 scene table, frame-major: within a grid step's block,
+    # frame b's segment i starts at (b * S + i) * _FIELDS. Mosaic tiles a
+    # rank-1 block in 128s and XLA lays a rank-1 SMEM operand out in tiles
+    # of 1024, so each block's table is padded to a 1024-word stride.
+    n_blocks = bp // bb
+    scene = jnp.concatenate(
+        [segs.astype(jnp.float32), intens.astype(jnp.float32)[..., None]],
+        axis=-1).reshape(n_blocks, bb * s * _FIELDS)
+    stride = pl.cdiv(bb * s * _FIELDS, 1024) * 1024
+    scene = jnp.pad(scene, ((0, 0), (0, stride - scene.shape[1]))).reshape(-1)
 
     out = pl.pallas_call(
         functools.partial(_raster_kernel, h=h, w=w, s=s, bb=bb),
-        grid=(bp // bb,),
-        in_specs=[
-            pl.BlockSpec((bb, s, 8), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bb, s), lambda i: (i, 0)),
-        ],
+        grid=(n_blocks,),
+        in_specs=[pl.BlockSpec((stride,), lambda i: (i,),
+                               memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((bb, h, wp), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, h, wp), jnp.float32),
         interpret=interpret,
-    )(segs8.astype(jnp.float32), intens.astype(jnp.float32))
+    )(scene)
     return out[:b, :, :w]

@@ -213,7 +213,9 @@ def measure(arch: str, shape_name: str, variant: str, multi_pod: bool = False) -
                "args_gib": ma.argument_size_in_bytes / 2**30}
     except (AttributeError, NotImplementedError):
         pass  # backend exposes no memory stats; anything else should raise
-    from benchmarks.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
+    from benchmarks.roofline import V5E, peaks
+
+    pk = peaks(V5E)
 
     from repro.configs.base import shape_by_name as _sbn
 
@@ -225,11 +227,11 @@ def measure(arch: str, shape_name: str, variant: str, multi_pod: bool = False) -
         "flops_per_device": hlo["flops"],
         "bytes_per_device": hlo["bytes"],
         "collective_bytes_per_device": hlo["collectives"],
-        "compute_s": hlo["flops"] / PEAK_FLOPS,
-        "memory_s": hlo["bytes"] / HBM_BW,
-        "collective_s": hlo["collectives"]["total"] / ICI_BW,
-        "score_traffic_s": score_b / HBM_BW,
-        "memory_s_flash": (hlo["bytes"] - score_b) / HBM_BW,
+        "compute_s": hlo["flops"] / pk["flops"],
+        "memory_s": hlo["bytes"] / pk["hbm_bw"],
+        "collective_s": hlo["collectives"]["total"] / pk["ici_bw"],
+        "score_traffic_s": score_b / pk["hbm_bw"],
+        "memory_s_flash": (hlo["bytes"] - score_b) / pk["hbm_bw"],
         **mem,
     }
     res["bound_s"] = max(res["compute_s"], res["memory_s"], res["collective_s"])

@@ -21,7 +21,6 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.env import Env
@@ -88,9 +87,9 @@ class ShardedEnvPool(EnvPool):
         def local_reset(k):
             return self._local.reset(self._shard_key(k))
 
-        state, obs = shard_map(
+        state, obs = jax.shard_map(
             local_reset, mesh=self.mesh, in_specs=P(),
-            out_specs=(self._bspec, self._bspec), check_rep=False,
+            out_specs=(self._bspec, self._bspec), check_vma=False,
         )(key)
         return PoolState(state, obs, jax.random.fold_in(key, 0x57EB))
 
@@ -104,12 +103,12 @@ class ShardedEnvPool(EnvPool):
                 self, state, a, self._shard_key(k), venv=self._local)
             return state, obs, rew, done, info
 
-        state, obs, rew, done, info = shard_map(
+        state, obs, rew, done, info = jax.shard_map(
             local_many, mesh=self.mesh,
             in_specs=(self._bspec, self._cspec, P()),
             out_specs=(self._bspec, self._cspec, self._cspec, self._cspec,
                        self._cspec),
-            check_rep=False,
+            check_vma=False,
         )(env_state, actions, key)
         return state, (obs, rew, done, info)
 
@@ -126,12 +125,12 @@ class ShardedEnvPool(EnvPool):
             ts = self._local.step(state, a, self._shard_key(k))
             return ts.state, ts.obs, ts.reward, ts.done, ts.info
 
-        state, obs, reward, done, info = shard_map(
+        state, obs, reward, done, info = jax.shard_map(
             local_step, mesh=self.mesh,
             in_specs=(self._bspec, self._bspec, P()),
             out_specs=(self._bspec, self._bspec, self._bspec, self._bspec,
                        self._bspec),
-            check_rep=False,
+            check_vma=False,
         )(carry.env_state, actions, key)
         return (PoolState(state, obs, next_key),
                 PoolStep(obs, reward, done, info))

@@ -247,3 +247,15 @@ def test_static_impact_accounting():
     assert imp.co2_g_per_mstep == pytest.approx(
         200.0 / 3.6e6 * 0.475 * 1e3)
     json.dumps(imp.report())
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    """The static model prices a v5e, by name; a device kind with no
+    published peaks raises instead of borrowing another chip's."""
+    from benchmarks.roofline import PEAKS, V5E, peaks
+    from repro.analysis.cost import TARGET_DEVICE_KIND
+
+    assert TARGET_DEVICE_KIND == V5E == "TPU v5 lite"
+    assert peaks(V5E) is PEAKS[V5E]
+    with pytest.raises(KeyError, match="TPU v4"):
+        peaks("TPU v4")
