@@ -129,6 +129,9 @@ def megastep_pallas(step_rows: Callable, state: jax.Array, actions: jax.Array,
             jax.ShapeDtypeStruct((k, bp), jnp.float32),
         ],
         interpret=interpret,
+        # fixed, not taken from the wrapped function: profiles name the
+        # kernel by it
+        name="_megastep_kernel",
     )(state.astype(jnp.float32), actions.astype(jnp.float32),
       fresh.astype(jnp.float32), fresh_obs.astype(jnp.float32))
 

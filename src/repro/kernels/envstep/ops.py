@@ -23,6 +23,14 @@ the fused body — one batched `kernels.raster` call over all K·B scenes per
 chunk — and the frame-stack ring is rebuilt with a cheap select scan.
 Everything stays on device; rendering work matches the vmap path exactly
 (one stepped + one fresh frame per env per step).
+
+Each sub-layer of the fused step runs under one `jax.named_scope`, which
+reaches the compiled program's `op_name` metadata and so a profile's ops:
+`cairl.reset` (the auto-reset key chain and fresh states), `cairl.layout`
+(pytree <-> kernel rows, output casts), `cairl.megastep` (the kernel call),
+`cairl.render` (scenes and the raster call) and `cairl.frame_stack` (the
+frame ring and auto-reset select). No name contains another, so a
+substring match finds each alone.
 """
 from __future__ import annotations
 
@@ -120,12 +128,13 @@ def _render_obs_rows(core, spec, obs_rows, backend):
 
     base = core.unwrapped
     k, _, b = obs_rows.shape
-    states = jax.vmap(spec.unflatten)(obs_rows)
-    segs, intens = jax.vmap(jax.vmap(base.scene))(states)
-    h, w = base.frame_shape
-    frames = rasterize(segs.reshape((k * b,) + segs.shape[2:]),
-                       intens.reshape(k * b, -1), h, w, backend=backend)
-    return frames.reshape(k, b, h, w)
+    with jax.named_scope("cairl.render"):
+        states = jax.vmap(spec.unflatten)(obs_rows)
+        segs, intens = jax.vmap(jax.vmap(base.scene))(states)
+        h, w = base.frame_shape
+        frames = rasterize(segs.reshape((k * b,) + segs.shape[2:]),
+                           intens.reshape(k * b, -1), h, w, backend=backend)
+        return frames.reshape(k, b, h, w)
 
 
 def _mask_inactive(old_state, new_state, ts, active):
@@ -196,7 +205,8 @@ def fused_step(env, state, actions, keys=None, num_steps: Optional[int] = None,
 
     acts = jnp.asarray(actions)
     if acts.ndim == 3 and acts.shape[-1] == 1:
-        acts = acts[..., 0]
+        with jax.named_scope("cairl.layout"):
+            acts = acts[..., 0]
     if acts.ndim != 2:
         raise ValueError(f"actions must be (K, B[, 1]); got {actions.shape}")
     k, b = acts.shape
@@ -215,8 +225,9 @@ def fused_step(env, state, actions, keys=None, num_steps: Optional[int] = None,
         fs, fo = jax.vmap(core.reset)(pair[:, 1])
         return pair[:, 0], (fs, fo)
 
-    final_keys, (fresh_states, fresh_obs) = jax.lax.scan(
-        reset_body, state.key, None, length=k)
+    with jax.named_scope("cairl.reset"):
+        final_keys, (fresh_states, fresh_obs) = jax.lax.scan(
+            reset_body, state.key, None, length=k)
 
     def to_rows(wrapped):
         if max_steps is None:
@@ -231,33 +242,40 @@ def fused_step(env, state, actions, keys=None, num_steps: Optional[int] = None,
         frames0 = core_state.frames                    # (B, N, H, W)
         core_state = core_state.inner
 
-    rows = to_rows(core_state)                         # (S', B)
-    fresh_rows = to_rows(fresh_states)                 # (K, S', B)
-    fobs_rows = jnp.swapaxes(fresh_obs, -1, -2)        # (K, O, B)
+    with jax.named_scope("cairl.layout"):
+        rows = to_rows(core_state)                     # (S', B)
+        fresh_rows = to_rows(fresh_states)             # (K, S', B)
+        fobs_rows = jnp.swapaxes(fresh_obs, -1, -2)    # (K, O, B)
 
-    new_rows, obs, tobs, reward, done, trunc = env_megastep(
-        spec.step_rows, rows, acts.astype(jnp.float32), fresh_rows, fobs_rows,
-        max_steps=max_steps, backend=backend, batch_block=batch_block)
+    with jax.named_scope("cairl.megastep"):
+        new_rows, obs, tobs, reward, done, trunc = env_megastep(
+            spec.step_rows, rows, acts.astype(jnp.float32), fresh_rows,
+            fobs_rows, max_steps=max_steps, backend=backend,
+            batch_block=batch_block)
 
-    inner = spec.unflatten(new_rows if max_steps is None
-                           else new_rows[:spec.state_size])
-    if max_steps is not None:
-        inner = TimeLimitState(inner, new_rows[spec.state_size].astype(jnp.int32))
-    done_b = done.astype(bool)
-    info = {}
-    if max_steps is not None:
-        info["truncated"] = trunc.astype(bool)
+    with jax.named_scope("cairl.layout"):
+        inner = spec.unflatten(new_rows if max_steps is None
+                               else new_rows[:spec.state_size])
+        if max_steps is not None:
+            inner = TimeLimitState(
+                inner, new_rows[spec.state_size].astype(jnp.int32))
+        done_b = done.astype(bool)
+        info = {}
+        if max_steps is not None:
+            info["truncated"] = trunc.astype(bool)
 
     if not pixels:
-        new_state = AutoResetState(inner, final_keys)
-        # The kernel computes in f32 rows; integer observation spaces (the
-        # grid suite's MultiDiscrete cell codes) get their dtype back here —
-        # values are small ints, exact through the f32 round-trip.
-        odt = core.observation_space.dtype
-        info["terminal_obs"] = jnp.swapaxes(tobs, -1, -2).astype(odt)
-        out = new_state, Timestep(
-            state=new_state, obs=jnp.swapaxes(obs, -1, -2).astype(odt),
-            reward=reward, done=done_b, info=info)
+        with jax.named_scope("cairl.layout"):
+            new_state = AutoResetState(inner, final_keys)
+            # The kernel computes in f32 rows; integer observation spaces
+            # (the grid suite's MultiDiscrete cell codes) get their dtype
+            # back here — values are small ints, exact through the f32
+            # round-trip.
+            odt = core.observation_space.dtype
+            info["terminal_obs"] = jnp.swapaxes(tobs, -1, -2).astype(odt)
+            out = new_state, Timestep(
+                state=new_state, obs=jnp.swapaxes(obs, -1, -2).astype(odt),
+                reward=reward, done=done_b, info=info)
         return out if active is None else _mask_inactive(state, *out,
                                                          active=active)
 
@@ -267,23 +285,25 @@ def fused_step(env, state, actions, keys=None, num_steps: Optional[int] = None,
     # path, minus all its per-step dispatch.
     pre = _render_obs_rows(core, spec, tobs, backend)        # (K, B, H, W)
     fresh_px = _render_obs_rows(core, spec, fobs_rows, backend)
-    if num_stack is None:
-        obs_px = jnp.where(done_b[..., None, None], fresh_px, pre)
-        tobs_px = pre
-        new_inner = inner
-    else:
-        def stack_body(frames, xs):
-            pre_f, fresh_f, d = xs
-            pre_stack = jnp.concatenate([frames[:, 1:], pre_f[:, None]],
-                                        axis=1)
-            post = jnp.where(d[:, None, None, None],
-                             jnp.broadcast_to(fresh_f[:, None],
-                                              pre_stack.shape), pre_stack)
-            return post, (post, pre_stack)
+    with jax.named_scope("cairl.frame_stack"):
+        if num_stack is None:
+            obs_px = jnp.where(done_b[..., None, None], fresh_px, pre)
+            tobs_px = pre
+            new_inner = inner
+        else:
+            def stack_body(frames, xs):
+                pre_f, fresh_f, d = xs
+                pre_stack = jnp.concatenate([frames[:, 1:], pre_f[:, None]],
+                                            axis=1)
+                post = jnp.where(d[:, None, None, None],
+                                 jnp.broadcast_to(fresh_f[:, None],
+                                                  pre_stack.shape),
+                                 pre_stack)
+                return post, (post, pre_stack)
 
-        frames_t, (obs_px, tobs_px) = jax.lax.scan(
-            stack_body, frames0, (pre, fresh_px, done_b))
-        new_inner = FrameStackState(inner, frames_t)
+            frames_t, (obs_px, tobs_px) = jax.lax.scan(
+                stack_body, frames0, (pre, fresh_px, done_b))
+            new_inner = FrameStackState(inner, frames_t)
     new_state = AutoResetState(new_inner, final_keys)
     info["terminal_obs"] = tobs_px
     out = new_state, Timestep(state=new_state, obs=obs_px, reward=reward,

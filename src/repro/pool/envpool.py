@@ -190,13 +190,16 @@ class EnvPool:
         `actions` is (K, B[, A]); outputs carry a leading (K, ...) axis.
         Equivalent to scanning `step` over the block (envs whose dynamics
         ignore the per-step key make the two paths bit-compatible)."""
-        if key is None:
-            next_key, key = jax.random.split(carry.key)
-        else:
-            next_key = carry.key
+        with jax.named_scope("cairl.layout"):
+            if key is None:
+                next_key, key = jax.random.split(carry.key)
+            else:
+                next_key = carry.key
         state, (obs, reward, done, info) = self._step_many_core(
             carry.env_state, actions, key)
-        return (PoolState(state, obs[-1], next_key),
+        with jax.named_scope("cairl.layout"):
+            last_obs = obs[-1]
+        return (PoolState(state, last_obs, next_key),
                 PoolStep(obs, reward, done, info))
 
     def xla(self) -> XlaPool:

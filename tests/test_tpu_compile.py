@@ -2,10 +2,14 @@
 
 No chip is needed: the TPU compiler is installed, and it compiles for a chip
 that is described, not attached. Each test compiles one kernel and asserts
-that the compiled program holds it (`tpu_custom_call`): the megastep for
-each of the 11 fused families at 4096 envs × 32 steps, and the rasteriser
-over 1024 frames of 84×84. What the chip's compiler refuses (a slice it
-cannot tile, an op it cannot lower, too much fast memory) fails here.
+that the compiled program holds it (`tpu_custom_call`) under the kernel's
+own name, the one a profile's reader (`bench/trace_reduce.kernel_of`)
+looks for: the megastep for each of the 11 fused families at 4096 envs ×
+32 steps, and the rasteriser over 1024 frames of 84×84. One more compiles
+the whole pooled Pong-v0 step, both kernels inside, and asserts that its
+ops keep the fused step's named scopes. What the chip's compiler refuses
+(a slice it cannot tile, an op it cannot lower, too much fast memory)
+fails here.
 
 Only one process at a time may load the TPU compiler's library. So the
 topology is described inside a module fixture, never while a module is
@@ -14,12 +18,15 @@ persistent compilation cache is off around these compiles: an entry
 written for a described chip cannot be read back without one.
 """
 import functools
+import importlib.util
 import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import make_vec
 from repro.core import make
 from repro.kernels.envstep.megastep import megastep_pallas
 from repro.kernels.envstep.specs import lookup
@@ -27,6 +34,28 @@ from repro.kernels.raster.raster import rasterize_pallas
 
 BATCH, UNROLL = 4096, 32
 FRAMES, SEGMENTS, HEIGHT, WIDTH = 1024, 6, 84, 84
+#: the pooled pixel step: envs and steps per chunk
+POOL_ENVS, POOL_UNROLL = 256, 4
+KERNELS = ("_megastep_kernel", "_raster_kernel")
+SCOPES = ("cairl.reset", "cairl.layout", "cairl.megastep", "cairl.render",
+          "cairl.frame_stack")
+
+
+@functools.cache
+def _trace_reduce():
+    """The benchmark's trace reducer, which names a profile's kernels."""
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench" /
+            "trace_reduce.py")
+    spec = importlib.util.spec_from_file_location("trace_reduce", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _kernels_in(text):
+    """The names of the Pallas kernels a compiled program calls."""
+    kernel_of = _trace_reduce().kernel_of
+    return {kernel_of(line, KERNELS) for line in text.splitlines()} - {""}
 
 #: one id per fused family (kernels/envstep/specs.py `_dynamics`)
 FUSED_IDS = ("CartPole-v1", "MountainCar-v0", "Pendulum-v1", "Acrobot-v1",
@@ -78,6 +107,7 @@ def test_megastep_compiles_for_v5e(env_id, one_chip):
         (rows, BATCH), (UNROLL, BATCH), (UNROLL, rows, BATCH),
         (UNROLL, spec.obs_size, BATCH), sharding=one_chip)
     assert "tpu_custom_call" in text, env_id
+    assert _kernels_in(text) == {"_megastep_kernel"}, env_id
 
 
 def test_rasteriser_compiles_for_v5e(one_chip):
@@ -85,3 +115,32 @@ def test_rasteriser_compiles_for_v5e(one_chip):
         functools.partial(rasterize_pallas, h=HEIGHT, w=WIDTH),
         (FRAMES, SEGMENTS, 5), (FRAMES, SEGMENTS), sharding=one_chip)
     assert "tpu_custom_call" in text
+    assert _kernels_in(text) == {"_raster_kernel"}
+
+
+@pytest.fixture
+def tpu_dispatch(monkeypatch):
+    """`backend="auto"` picks the Pallas kernels, as it does on a chip.
+    Traces cached under either choice are dropped before and after."""
+    from repro.kernels.envstep import ops as envstep_ops
+    from repro.kernels.raster import ops as raster_ops
+
+    jax.clear_caches()
+    monkeypatch.setattr(envstep_ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(raster_ops, "on_tpu", lambda: True)
+    yield
+    jax.clear_caches()
+
+
+def test_pooled_pixel_step_keeps_its_scopes_for_v5e(one_chip, tpu_dispatch):
+    h = make_vec("Pong-v0", POOL_ENVS, unroll=POOL_UNROLL).xla()
+    placed = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                             sharding=one_chip)
+    carry = jax.tree.map(placed, jax.eval_shape(
+        h.init, jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    acts = placed(jax.ShapeDtypeStruct((POOL_UNROLL, POOL_ENVS), jnp.int32))
+    text = jax.jit(h.step_many).lower(carry, acts).compile().as_text()
+    assert _kernels_in(text) == set(KERNELS)
+    op_names = {op for op, _ in _trace_reduce().hlo_op_names([text]).values()}
+    for scope in SCOPES:
+        assert any(scope in op for op in op_names), scope
