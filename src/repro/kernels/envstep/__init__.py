@@ -10,6 +10,8 @@ Structure mirrors kernels/raster and kernels/attention: megastep.py
 (pl.pallas_call + BlockSpec), ref.py (pure-jnp oracle), ops.py (dispatching
 wrapper with an interpret=True CPU mode), specs.py (per-env row dynamics;
 the row *layout* is auto-derived from a traced reset — `derive_layout`).
+framestack.py is the pixel pipeline's frame-stack kernel and its scan
+reference, dispatched by `ops.frame_stack`.
 """
 from repro.kernels.envstep.megastep import fused_transition, megastep_pallas
 from repro.kernels.envstep.ops import env_megastep, fused_step, supports

@@ -20,7 +20,9 @@ suite) fuse too, when the core spec's obs rows are its state rows
 (`FusedSpec.obs_is_state`): the kernel advances the row-major game logic for
 the whole K-step chunk, then the per-step frames are rasterised *outside*
 the fused body — one batched `kernels.raster` call over all K·B scenes per
-chunk — and the frame-stack ring is rebuilt with a cheap select scan.
+chunk — and the frame-stack ring and auto-reset select run in one pass
+(`frame_stack`: the `_frame_stack_kernel` Pallas call of framestack.py, which
+writes each stacked frame once, or its `lax.scan` reference under "jnp").
 Everything stays on device; rendering work matches the vmap path exactly
 (one stepped + one fresh frame per env per step).
 
@@ -29,8 +31,8 @@ reaches the compiled program's `op_name` metadata and so a profile's ops:
 `cairl.reset` (the auto-reset key chain and fresh states), `cairl.layout`
 (pytree <-> kernel rows, output casts), `cairl.megastep` (the kernel call),
 `cairl.render` (scenes and the raster call) and `cairl.frame_stack` (the
-frame ring and auto-reset select). No name contains another, so a
-substring match finds each alone.
+frame ring and auto-reset select, the frame-stack kernel call). No name
+contains another, so a substring match finds each alone.
 """
 from __future__ import annotations
 
@@ -40,6 +42,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import on_tpu
+from repro.kernels.envstep.framestack import (frame_stack_pallas,
+                                              frame_stack_ref)
 from repro.kernels.envstep.megastep import megastep_pallas
 from repro.kernels.envstep.ref import megastep_ref
 from repro.kernels.envstep.specs import lookup
@@ -65,6 +69,24 @@ def env_megastep(step_rows, state, actions, fresh, fresh_obs, *,
     if backend == "jnp":
         return megastep_ref(step_rows, state, actions, fresh, fresh_obs,
                             max_steps=max_steps)
+    raise ValueError(f"unknown backend {backend!r}")
+
+
+def frame_stack(frames, pre, fresh, done, *, backend: str = "auto"):
+    """The pixel pipeline's frame-stack ring with backend dispatch
+    (framestack.py): (B, N, H, W) carried stack, (K, B, H, W) stepped and
+    fresh frames, (K, B) done -> (new stack, obs, terminal obs).
+
+    backend: "auto" (pallas on TPU, jnp elsewhere) | "pallas" |
+    "pallas_interpret" | "jnp".
+    """
+    if backend == "auto":
+        backend = "pallas" if on_tpu() else "jnp"
+    if backend in ("pallas", "pallas_interpret"):
+        return frame_stack_pallas(frames, pre, fresh, done,
+                                  interpret=backend == "pallas_interpret")
+    if backend == "jnp":
+        return frame_stack_ref(frames, pre, fresh, done)
     raise ValueError(f"unknown backend {backend!r}")
 
 
@@ -118,11 +140,15 @@ def supports(env) -> bool:
 
 
 def _render_obs_rows(core, spec, obs_rows, backend):
-    """(K, O, B) obs rows -> (K, B, H, W) frames, one batched raster call.
+    """(K, O, B) obs rows -> (K·B, H, W) frames, step-major, one batched
+    raster call.
 
     Valid because `spec.obs_is_state`: obs rows ARE state rows, so the
     capsule scene of every step is reconstructable on device from the
     kernel's per-step obs output — no per-step render inside the fused body.
+    The frames are handed over flat: the consumer's (K, B) view is taken
+    under its own scope, since XLA merges consecutive reshapes into one op
+    that would otherwise carry both scopes.
     """
     from repro.kernels.raster import rasterize
 
@@ -132,9 +158,8 @@ def _render_obs_rows(core, spec, obs_rows, backend):
         states = jax.vmap(spec.unflatten)(obs_rows)
         segs, intens = jax.vmap(jax.vmap(base.scene))(states)
         h, w = base.frame_shape
-        frames = rasterize(segs.reshape((k * b,) + segs.shape[2:]),
-                           intens.reshape(k * b, -1), h, w, backend=backend)
-        return frames.reshape(k, b, h, w)
+        return rasterize(segs.reshape((k * b,) + segs.shape[2:]),
+                         intens.reshape(k * b, -1), h, w, backend=backend)
 
 
 def _mask_inactive(old_state, new_state, ts, active):
@@ -283,26 +308,18 @@ def fused_step(env, state, actions, keys=None, num_steps: Optional[int] = None,
     # frames in two batched on-device calls, then apply the frame-stack ring
     # and auto-reset selection — the same per-step render count as the vmap
     # path, minus all its per-step dispatch.
-    pre = _render_obs_rows(core, spec, tobs, backend)        # (K, B, H, W)
+    pre = _render_obs_rows(core, spec, tobs, backend)        # (K·B, H, W)
     fresh_px = _render_obs_rows(core, spec, fobs_rows, backend)
     with jax.named_scope("cairl.frame_stack"):
+        pre, fresh_px = (x.reshape((k, b) + x.shape[1:])
+                         for x in (pre, fresh_px))
         if num_stack is None:
             obs_px = jnp.where(done_b[..., None, None], fresh_px, pre)
             tobs_px = pre
             new_inner = inner
         else:
-            def stack_body(frames, xs):
-                pre_f, fresh_f, d = xs
-                pre_stack = jnp.concatenate([frames[:, 1:], pre_f[:, None]],
-                                            axis=1)
-                post = jnp.where(d[:, None, None, None],
-                                 jnp.broadcast_to(fresh_f[:, None],
-                                                  pre_stack.shape),
-                                 pre_stack)
-                return post, (post, pre_stack)
-
-            frames_t, (obs_px, tobs_px) = jax.lax.scan(
-                stack_body, frames0, (pre, fresh_px, done_b))
+            frames_t, obs_px, tobs_px = frame_stack(
+                frames0, pre, fresh_px, done_b, backend=backend)
             new_inner = FrameStackState(inner, frames_t)
     new_state = AutoResetState(new_inner, final_keys)
     info["terminal_obs"] = tobs_px

@@ -5,9 +5,10 @@ that is described, not attached. Each test compiles one kernel and asserts
 that the compiled program holds it (`tpu_custom_call`) under the kernel's
 own name, the one a profile's reader (`bench/trace_reduce.kernel_of`)
 looks for: the megastep for each of the 11 fused families at 4096 envs ×
-32 steps, and the rasteriser over 1024 frames of 84×84. One more compiles
-the whole pooled Pong-v0 step, both kernels inside, and asserts that its
-ops keep the fused step's named scopes. What the chip's compiler refuses
+32 steps, the rasteriser over 1024 frames of 84×84, and the frame stack at
+1024 envs × 8 steps of 4×84×84 frames. One more compiles the whole pooled
+Pong-v0 step, all three kernels inside, and asserts that its ops keep the
+fused step's named scopes. What the chip's compiler refuses
 (a slice it cannot tile, an op it cannot lower, too much fast memory)
 fails here.
 
@@ -28,6 +29,7 @@ import pytest
 
 from repro import make_vec
 from repro.core import make
+from repro.kernels.envstep.framestack import frame_stack_pallas
 from repro.kernels.envstep.megastep import megastep_pallas
 from repro.kernels.envstep.specs import lookup
 from repro.kernels.raster.raster import rasterize_pallas
@@ -36,7 +38,9 @@ BATCH, UNROLL = 4096, 32
 FRAMES, SEGMENTS, HEIGHT, WIDTH = 1024, 6, 84, 84
 #: the pooled pixel step: envs and steps per chunk
 POOL_ENVS, POOL_UNROLL = 256, 4
-KERNELS = ("_megastep_kernel", "_raster_kernel")
+#: the frame stack at the pixel cell's size: envs, steps, stack depth
+STACK_ENVS, STACK_UNROLL, STACK = 1024, 8, 4
+KERNELS = ("_megastep_kernel", "_raster_kernel", "_frame_stack_kernel")
 SCOPES = ("cairl.reset", "cairl.layout", "cairl.megastep", "cairl.render",
           "cairl.frame_stack")
 
@@ -84,9 +88,12 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compiled_text(fn, *shapes, sharding):
-    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
-            for s in shapes]
+def _compiled_text(fn, *shapes, sharding, dtypes=()):
+    """`fn` compiled for f32 arguments of `shapes`, or of `dtypes` where
+    given."""
+    dtypes = dtypes or (jnp.float32,) * len(shapes)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in zip(shapes, dtypes)]
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
@@ -116,6 +123,20 @@ def test_rasteriser_compiles_for_v5e(one_chip):
         (FRAMES, SEGMENTS, 5), (FRAMES, SEGMENTS), sharding=one_chip)
     assert "tpu_custom_call" in text
     assert _kernels_in(text) == {"_raster_kernel"}
+
+
+@pytest.mark.parametrize("b", (STACK_ENVS, 130))
+def test_frame_stack_compiles_for_v5e(b, one_chip):
+    """At the cell's size, and at a batch off the 128-lane tile, which
+    Mosaic's strided loads refuse unless it is padded."""
+    k, n = STACK_UNROLL, STACK
+    f32 = jnp.float32
+    text = _compiled_text(
+        frame_stack_pallas, (b, n, HEIGHT, WIDTH), (k, b, HEIGHT, WIDTH),
+        (k, b, HEIGHT, WIDTH), (k, b), sharding=one_chip,
+        dtypes=(f32, f32, f32, jnp.bool_))
+    assert "tpu_custom_call" in text
+    assert _kernels_in(text) == {"_frame_stack_kernel"}
 
 
 @pytest.fixture
