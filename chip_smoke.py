@@ -16,7 +16,10 @@ pools, `dqn.train_compiled(..., fused=True)` and `ppo.train(..., fused=True)`.
   - rasteriser: 1024 84x84 frames, Pallas against the jnp reference.
   - fused training: DQN with the paper's Table I widths (32x32 MLP, 50k
     replay) over 256 envs on the Pallas env engine, and PPO on CartPole-v1
-    with the `PPOConfig` defaults; a few chunks each, finite metrics.
+    with the `PPOConfig` defaults; a few chunks each, finite metrics. One
+    more DQN chunk of 100 steps is judged by the benchmark's reference
+    (`bench/reference/dqn_cartpole.py`) within the `dqn-cartpole-v1`
+    configuration's limits, so a learner that drifts from float32 fails.
   - async pool: a few send/recv rounds of CartPole-v1 over 256 slots.
   - `--chips 4`: `ShardedEnvPool` of CartPole-v1 over 16384 envs on a
     4-device mesh, Pallas against vmap on the same mesh, and the carry
@@ -214,6 +217,41 @@ def raster_phase(frames: int = 1024, segments: int = 6) -> None:
         say(f"  matches jnp reference: max float diff {worst!r}")
 
 
+def dqn_reference_check(cfg, state, apply_fn, n: int) -> None:
+    """One more fused chunk of `n` train steps from `state`, judged by the
+    benchmark's plain reference of the `dqn-cartpole-v1` configuration."""
+    import importlib.util
+    import pathlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import make
+    from repro.rl import dqn
+    from repro.train.fused import fused_train_chunk
+
+    bench = pathlib.Path(__file__).resolve().parent / "bench"
+    spec = importlib.util.spec_from_file_location(
+        "dqn_cartpole_reference", bench / "reference" / "dqn_cartpole.py")
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    config = json.loads((bench / "configs" / "dqn-cartpole-v1.json")
+                        .read_text())
+    bound = ref.bind(ref.hparams(config, cfg.num_envs))
+    run_chunk = fused_train_chunk(
+        dqn.make_train_step(make("CartPole-v1"), apply_fn, cfg))
+    before = jax.tree.map(jnp.copy, state)
+    after, metrics = run_chunk(state, n)
+    steps = jnp.arange(n, dtype=jnp.int32)
+    gap, bad = jax.jit(bound.check)(before, steps, metrics, after)
+    limits = config["limits"]
+    say(f"  one more chunk against the reference: gap {float(gap)!r} "
+        f"(limit {limits['gap']}), mismatches {int(bad)} "
+        f"(limit {limits['mismatches']})")
+    assert float(gap) <= limits["gap"] and int(bad) <= limits["mismatches"], \
+        "fused DQN drifted from the float32 reference"
+
+
 def train_phases() -> None:
     import dataclasses
 
@@ -234,7 +272,7 @@ def train_phases() -> None:
             f"replay={cfg.memory_size} steps={steps} chunk={chunk} "
             f"tpu_custom_call={has_kernel}")
         assert has_kernel, "fused DQN with env_backend='pallas' has no kernel"
-        state, _, metrics = dqn.train_compiled(
+        state, apply_fn, metrics = dqn.train_compiled(
             make("CartPole-v1"), cfg, steps, jax.random.PRNGKey(0),
             chunk=chunk, fused=True)
         jax.block_until_ready((state, metrics))
@@ -244,6 +282,7 @@ def train_phases() -> None:
         assert_finite(metrics, "dqn metrics")
         say(f"  final loss {float(metrics['loss'][-1])!r} "
             f"return {float(metrics['return'][-1])!r}")
+        dqn_reference_check(cfg, state, apply_fn, chunk)
 
     with Phase("train:ppo-fused"):
         cfg = ppo.PPOConfig()

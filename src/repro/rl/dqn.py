@@ -152,43 +152,54 @@ def make_train_step(env: Env, apply_fn, cfg: DQNConfig):
     learn = make_learn_step(apply_fn, cfg)
 
     def step_fn(state: DQNState, _, lr=None):
-        key, k_eps, k_act, k_env, k_sample = jax.random.split(state.key, 5)
-        eps = _epsilon(cfg, state.step)
-        obs = state.pool.obs
-        q = apply_fn(state.params, obs)
-        greedy = jnp.argmax(q, axis=-1).astype(jnp.int32)
-        randa = jax.random.randint(k_act, (cfg.num_envs,), 0, env.action_space.n)
-        explore = jax.random.uniform(k_eps, (cfg.num_envs,)) < eps
-        action = jnp.where(explore, randa, greedy)
+        # Each sub-layer runs under one named scope (cairl.act, cairl.env,
+        # cairl.replay, cairl.learn), which reaches every op's metadata in
+        # the compiled program: a device profile attributes its time by it.
+        with jax.named_scope("cairl.act"):
+            key, k_eps, k_act, k_env, k_sample = jax.random.split(state.key, 5)
+            eps = _epsilon(cfg, state.step)
+            obs = state.pool.obs
+            q = apply_fn(state.params, obs)
+            greedy = jnp.argmax(q, axis=-1).astype(jnp.int32)
+            randa = jax.random.randint(k_act, (cfg.num_envs,), 0, env.action_space.n)
+            explore = jax.random.uniform(k_eps, (cfg.num_envs,)) < eps
+            action = jnp.where(explore, randa, greedy)
 
-        new_pool, ts = pool.step(state.pool, action, k_env)
-        terminal_obs = ts.info.get("terminal_obs", ts.obs)
-        # Store the *termination* flag, not the folded done: truncated
-        # episodes (info["truncated"], core/wrappers.TimeLimit) still
-        # bootstrap through terminal_obs in _td_loss.
-        truncated = ts.info.get("truncated", jnp.zeros_like(ts.done))
-        terminal = ts.done & ~truncated
-        replay = replay_add_batch(state.replay, obs, action, ts.reward, terminal_obs, terminal)
+        with jax.named_scope("cairl.env"):
+            new_pool, ts = pool.step(state.pool, action, k_env)
+
+        with jax.named_scope("cairl.replay"):
+            terminal_obs = ts.info.get("terminal_obs", ts.obs)
+            # Store the *termination* flag, not the folded done: truncated
+            # episodes (info["truncated"], core/wrappers.TimeLimit) still
+            # bootstrap through terminal_obs in _td_loss.
+            truncated = ts.info.get("truncated", jnp.zeros_like(ts.done))
+            terminal = ts.done & ~truncated
+            replay = replay_add_batch(state.replay, obs, action, ts.reward, terminal_obs, terminal)
+            batch = replay_sample(replay, k_sample, cfg.batch_size)
 
         # learn (skipped while the buffer warms up)
-        batch = replay_sample(replay, k_sample, cfg.batch_size)
-        can_learn = replay.size >= cfg.learn_start
-        new_params, new_opt, loss = learn(state.params, state.target,
-                                          state.opt, batch, lr=lr)
-        params = jax.tree.map(lambda n, o: jnp.where(can_learn, n, o), new_params, state.params)
-        opt = jax.tree.map(lambda n, o: jnp.where(can_learn, n, o), new_opt, state.opt)
+        with jax.named_scope("cairl.learn"):
+            can_learn = replay.size >= cfg.learn_start
+            new_params, new_opt, loss = learn(state.params, state.target,
+                                              state.opt, batch, lr=lr)
+            params = jax.tree.map(lambda n, o: jnp.where(can_learn, n, o), new_params, state.params)
+            opt = jax.tree.map(lambda n, o: jnp.where(can_learn, n, o), new_opt, state.opt)
 
-        # periodic hard target sync (Table I: every 150 steps)
-        sync = (state.step % cfg.target_update_freq) == 0
-        target = jax.tree.map(lambda t, p: jnp.where(sync, p, t), state.target, params)
+            # periodic hard target sync (Table I: every 150 steps)
+            sync = (state.step % cfg.target_update_freq) == 0
+            target = jax.tree.map(lambda t, p: jnp.where(sync, p, t), state.target, params)
 
-        ep_return = state.ep_return + ts.reward
-        last_return = jnp.where(ts.done, ep_return, state.last_return)
-        ep_return = jnp.where(ts.done, 0.0, ep_return)
+        with jax.named_scope("cairl.act"):
+            ep_return = state.ep_return + ts.reward
+            last_return = jnp.where(ts.done, ep_return, state.last_return)
+            ep_return = jnp.where(ts.done, 0.0, ep_return)
+            step = state.step + 1
+            mean_return = jnp.mean(last_return)
 
         new_state = DQNState(params, target, opt, replay, new_pool, key,
-                             state.step + 1, ep_return, last_return)
-        metrics = {"loss": loss, "eps": eps, "return": jnp.mean(last_return)}
+                             step, ep_return, last_return)
+        metrics = {"loss": loss, "eps": eps, "return": mean_return}
         return new_state, metrics
 
     return step_fn

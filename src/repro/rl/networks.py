@@ -38,9 +38,13 @@ def mlp_init(key, sizes: Sequence[int], dtype=jnp.float32):
 
 
 def mlp_apply(params, x, activation: str = "elu"):
+    """The MLP in float32. The dots ask for HIGHEST precision: at DEFAULT a
+    TPU computes a float32 matmul in one bfloat16 pass, below the float32
+    the learner states. Other backends compute float32 dots either way."""
     act = Activation[activation]
     for i, layer in enumerate(params):
-        x = x @ layer["w"] + layer["b"]
+        x = jnp.matmul(x, layer["w"],
+                       precision=jax.lax.Precision.HIGHEST) + layer["b"]
         if i < len(params) - 1:
             x = act(x)
     return x

@@ -8,7 +8,9 @@ looks for: the megastep for each of the 11 fused families at 4096 envs ×
 32 steps, the rasteriser over 1024 frames of 84×84, and the frame stack at
 1024 envs × 8 steps of 4×84×84 frames. One more compiles the whole pooled
 Pong-v0 step, all three kernels inside, and asserts that its ops keep the
-fused step's named scopes. What the chip's compiler refuses
+fused step's named scopes; one the fused DQN chunk of the
+`dqn-cartpole-train` cell, whose ops keep the train step's four scopes,
+which leave its instructions as they are. What the chip's compiler refuses
 (a slice it cannot tile, an op it cannot lower, too much fast memory)
 fails here.
 
@@ -165,3 +167,21 @@ def test_pooled_pixel_step_keeps_its_scopes_for_v5e(one_chip, tpu_dispatch):
     op_names = {op for op, _ in _trace_reduce().hlo_op_names([text]).values()}
     for scope in SCOPES:
         assert any(scope in op for op in op_names), scope
+
+
+def test_dqn_chunk_keeps_its_scopes_for_v5e(one_chip, tpu_dispatch):
+    """The cell's 256 envs over the megastep, Table I's learner. The scopes
+    are metadata here too: without them the chunk holds the same
+    instructions. (At the default precision the compiler rounds the dots'
+    float32 operands to bfloat16 first, so their shapes differ from
+    these.)"""
+    from test_trace_scopes import (assert_dqn_scoped, dqn_chunk_text,
+                                   instruction_multiset, without_dqn_scopes)
+
+    text = dqn_chunk_text(256, 4, sharding=one_chip)
+    assert _kernels_in(text) == {"_megastep_kernel"}
+    assert_dqn_scoped(text)
+    assert "operand_precision={highest,highest}" in text
+    with without_dqn_scopes():
+        bare = dqn_chunk_text(256, 4, sharding=one_chip)
+    assert instruction_multiset(text) == instruction_multiset(bare)

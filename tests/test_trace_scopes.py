@@ -1,12 +1,19 @@
-"""The fused env step names its sub-layers inside the compiled program.
+"""The fused env step and the DQN train step name their sub-layers inside
+the compiled program.
 
 `fused_step` and the pool's `step_many` put every op they emit under one
 `jax.named_scope`; the scope reaches each HLO instruction's `op_name`
 metadata, which is how a profile of the chip attributes device time to a
 sub-layer (a substring match on the op's name path). Checked here on the
 CPU's compiled program: one chip's worth of the pool for a state env and a
-pixel env, and the sharded pool on four virtual CPU devices.
+pixel env, and the sharded pool on four virtual CPU devices. The DQN train
+step (`rl/dqn.make_train_step`) puts its act, env, replay and learn parts
+under four more, the env step's scopes nested in its env part; they are
+metadata, so the fused chunk holds the same instructions as without them.
 """
+import collections
+import contextlib
+import dataclasses
 import os
 import pathlib
 import re
@@ -18,6 +25,10 @@ import jax.numpy as jnp
 import pytest
 
 from repro import make_vec
+from repro.configs.cairl_dqn import PAPER_TABLE_I
+from repro.core import make
+from repro.rl import dqn
+from repro.train.fused import fused_train_chunk
 
 SCOPES = ("cairl.reset", "cairl.layout", "cairl.megastep", "cairl.render",
           "cairl.frame_stack")
@@ -33,6 +44,10 @@ _SHARD_MAP_UNNAMED = re.compile(r".*/shard_map(/[\w\-]+\.\d+)?")
 #: not computations: leaves and tuple plumbing of the program
 _NOT_COMPUTE = {"parameter", "constant", "get-tuple-element", "tuple"}
 ROOT = pathlib.Path(__file__).resolve().parents[1]
+DQN_SCOPES = ("cairl.act", "cairl.env", "cairl.replay", "cairl.learn")
+#: an HLO instruction's opcode and result shape
+_OPCODE_SHAPE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.*?)\s"
+                           r"([\w\-]+)\(")
 
 
 def step_many_text(env_id, num_envs, unroll, mesh=None) -> str:
@@ -120,3 +135,90 @@ def test_an_unscoped_op_is_caught():
                    ("multiply", "jit(f)/mul")]
     with pytest.raises(AssertionError):
         assert_scoped(line, pixels=False)
+
+
+# -- the DQN train step ---------------------------------------------------------
+def dqn_chunk_text(num_envs=16, n=4, sharding=None, apply_fn=None) -> str:
+    """The compiled fused chunk of Table I's DQN on CartPole-v1 over the
+    fused pool, with the carry placed on `sharding`'s device when given."""
+    env = make("CartPole-v1")
+    cfg = dataclasses.replace(PAPER_TABLE_I, num_envs=num_envs,
+                              env_backend="auto")
+    fns = []
+
+    def init(key):
+        state, fn = dqn.dqn_init(env, cfg, key)
+        fns.append(fn)
+        return state
+
+    carry = jax.eval_shape(init, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    if sharding is not None:
+        carry = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sharding), carry)
+    step = dqn.make_train_step(env, apply_fn or fns[0], cfg)
+    return fused_train_chunk(step).lower(carry, n).compile().as_text()
+
+
+def assert_dqn_scoped(text) -> None:
+    """Each of the four scopes holds ops; no op holds two of them, and the
+    env step's own scopes lie inside `cairl.env`."""
+    seen = set()
+    for opcode, op_name in scoped_ops(text):
+        held = [s for s in DQN_SCOPES if s in op_name]
+        assert len(held) <= 1, (opcode, op_name)
+        if any(s in op_name for s in SCOPES):
+            assert held == ["cairl.env"], (opcode, op_name)
+        seen.update(held)
+    assert seen == set(DQN_SCOPES)
+
+
+def instruction_multiset(text) -> collections.Counter:
+    """(opcode, result shape) of every instruction of a compiled program."""
+    return collections.Counter(
+        (m.group(2), m.group(1)) for m in map(_OPCODE_SHAPE.match,
+                                              text.splitlines()) if m)
+
+
+@contextlib.contextmanager
+def without_dqn_scopes():
+    """`jax.named_scope` of the train step's four scopes does nothing."""
+    real = jax.named_scope
+
+    def scope(name):
+        return contextlib.nullcontext() if name in DQN_SCOPES else real(name)
+
+    jax.named_scope = scope
+    try:
+        yield
+    finally:
+        jax.named_scope = real
+
+
+def parent_mlp_apply(params, x, activation="elu"):
+    """`mlp_apply` with its dots at the default precision."""
+    for i, layer in enumerate(params):
+        x = x @ layer["w"] + layer["b"]
+        if i < len(params) - 1:
+            x = jax.nn.elu(x)
+    return x
+
+
+def test_every_op_of_the_dqn_step_has_one_learner_scope():
+    assert_dqn_scoped(dqn_chunk_text())
+
+
+def test_dqn_scopes_and_precision_change_no_instruction():
+    """On the CPU, the chunk with the scopes and HIGHEST dots holds the
+    same instructions as the one without them, at the default precision."""
+    scoped = dqn_chunk_text()
+    with without_dqn_scopes():
+        bare = dqn_chunk_text(apply_fn=lambda p, x: parent_mlp_apply(p, x))
+    assert all(s not in bare for s in DQN_SCOPES)
+    assert instruction_multiset(scoped) == instruction_multiset(bare)
+
+
+def test_no_dqn_scope_name_holds_another():
+    names = SCOPES + DQN_SCOPES
+    for a in names:
+        for b in names:
+            assert a == b or a not in b
