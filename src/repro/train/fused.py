@@ -150,15 +150,27 @@ def _as_fleet(grid, default_lr: float) -> Fleet:
 
 
 def _algo_parts(env: Env, algo: str, cfg):
-    """(cfg, init_row(seed)->state, step_fn(state, _, lr=)->.. ) per algo."""
+    """(cfg, init_row(seed)->state, step_fn(state, _, lr=)->.., row_axes)
+
+    `row_axes` is the fleet's vmap axis for each leaf of a row's state, as
+    a pytree prefix: 0 where rows differ, None where every row holds the
+    same value at every step.
+    """
     if algo == "dqn":
         from repro.rl import dqn as _dqn
+        from repro.rl.replay import ReplayState
 
         cfg = cfg or _dqn.DQNConfig()
         _, apply_fn = _dqn._build_net(env, cfg, jax.random.PRNGKey(0))
         step_fn = _dqn.make_train_step(env, apply_fn, cfg)
         init_row = lambda key: _dqn.dqn_init(env, cfg, key)[0]
-        return cfg, init_row, step_fn
+        # Every row adds num_envs transitions a step, so the ring's cursor
+        # is the same in all of them. Left unbatched, it keeps the ring's
+        # write a block at one row: a batched start would make each
+        # dynamic_update_slice a scatter.
+        row_axes = _dqn.DQNState(*(0,) * len(_dqn.DQNState._fields))._replace(
+            replay=ReplayState(0, 0, 0, 0, 0, ptr=None, size=None))
+        return cfg, init_row, step_fn, row_axes
     if algo == "ppo":
         from repro.rl import ppo as _ppo
 
@@ -166,8 +178,26 @@ def _algo_parts(env: Env, algo: str, cfg):
         body = _ppo.make_update_body(env, cfg)
         step_fn = lambda state, _, lr=None: body(state, lr=lr)
         init_row = lambda key: _ppo.ppo_init(env, cfg, key)
-        return cfg, init_row, step_fn
+        return cfg, init_row, step_fn, 0
     raise ValueError(f"unknown fleet algo {algo!r}; expected 'dqn' or 'ppo'")
+
+
+def _fleet_chunk(step_fn: Callable, row_axes) -> Callable:
+    """`run_chunk((states, lr), n)`: n train steps of every fleet row, one
+    donated program; `row_axes` as `_algo_parts` gives it."""
+
+    def row_body(carry, _):
+        state, lr = carry
+        state, metrics = step_fn(state, None, lr=lr)
+        return (state, lr), metrics
+
+    @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(0,))
+    def run_chunk(carry, n: int):
+        return jax.vmap(lambda c: jax.lax.scan(row_body, c, None, length=n),
+                        in_axes=((row_axes, 0),),
+                        out_axes=((row_axes, 0), 0))(carry)
+
+    return run_chunk
 
 
 def fleet(env: Union[Env, str], grid, steps: int, *, algo: str = "dqn",
@@ -189,20 +219,11 @@ def fleet(env: Union[Env, str], grid, steps: int, *, algo: str = "dqn",
     """
     if isinstance(env, str):
         env = registry_make(env)
-    cfg, init_row, step_fn = _algo_parts(env, algo, cfg)
+    cfg, init_row, step_fn, row_axes = _algo_parts(env, algo, cfg)
     fl = _as_fleet(grid, cfg.lr)
-
-    def row_body(carry, _):
-        state, lr = carry
-        state, metrics = step_fn(state, None, lr=lr)
-        return (state, lr), metrics
-
-    @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(0,))
-    def run_chunk(carry, n: int):
-        return jax.vmap(lambda c: jax.lax.scan(row_body, c, None, length=n))(
-            carry)
-
-    states = jax.vmap(lambda s: init_row(jax.random.PRNGKey(s)))(fl.seed)
+    run_chunk = _fleet_chunk(step_fn, row_axes)
+    states = jax.vmap(lambda s: init_row(jax.random.PRNGKey(s)),
+                      out_axes=row_axes)(fl.seed)
     # copy lr into the carry: the chunk donates its whole carry, and the
     # caller's grid.lr must survive the call (states are freshly built here)
     carry = _donate_safe((states, jnp.array(fl.lr, copy=True)))
@@ -214,6 +235,11 @@ def fleet(env: Union[Env, str], grid, steps: int, *, algo: str = "dqn",
         all_metrics.append(metrics)
         done += n
     states, _ = carry
+    # leaves shared by every row get the (F,) fleet axis back
+    states = jax.tree.map(
+        lambda axis, sub: sub if axis == 0 else jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (fl.width,) + x.shape), sub),
+        row_axes, states, is_leaf=lambda axis: axis is None)
     metrics = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=1),
                            *all_metrics)
     return states, metrics
@@ -260,7 +286,7 @@ def lower_train_chunk(algo: str, env_id: str, cfg=None, chunk: int = 8):
     env = registry_make(env_id)
     if cfg is None:
         _, _, cfg, _ = golden_train_setup(f"{algo}/{env_id}")
-    _, init_row, step_fn = _algo_parts(env, algo, cfg)
+    _, init_row, step_fn, _ = _algo_parts(env, algo, cfg)
     carry = jax.eval_shape(init_row, _KEY_SDS)
     run_chunk = fused_train_chunk(step_fn)
     return run_chunk.lower(carry, chunk), carry
